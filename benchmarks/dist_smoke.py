@@ -53,8 +53,10 @@ def main():
 
     from repro.configs import fidelity_presets, get_smoke
     from repro.data import SyntheticLMDataset
+    from repro.launch.mesh import make_mesh
     from repro.optim import PantherConfig
     from repro.optim.schedules import constant
+    from repro.plan import default_rules
     from repro.train.step import (batch_specs, make_train_step,
                                   train_state_init, train_state_specs)
 
@@ -79,7 +81,8 @@ def main():
 
     # 1-way: the single-host simulator path
     state = train_state_init(cfg, opt, jax.random.PRNGKey(0))
-    step1 = jax.jit(make_train_step(cfg, opt, constant(0.3), fidelity=fid))
+    step1 = jax.jit(make_train_step(cfg, opt, constant(0.3),
+                                    plan_rules=default_rules(opt, fidelity=fid)))
     losses1, us1 = _timed_steps(step1, state, batches)
     results["fidelity_1way"] = {
         "us_per_step": us1, "tokens_per_sec": tokens / (us1 * 1e-6),
@@ -88,17 +91,18 @@ def main():
 
     # 8-way: the same loop pjit-sharded (tokens over 'data', tiles over 'model')
     if n_dev >= 8:
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         named = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                        is_leaf=lambda x: isinstance(x, P))
         step8 = make_train_step(cfg, opt, constant(0.3), mesh=mesh,
-                                global_batch=B, fidelity=fid)
-        with mesh:
-            state = train_state_init(cfg, opt, jax.random.PRNGKey(0))
+                                global_batch=B,
+                                plan_rules=default_rules(opt, fidelity=fid))
+        sspecs = named(train_state_specs(cfg, opt, mesh))
+        with jax.set_mesh(mesh):
+            state = jax.device_put(train_state_init(cfg, opt, jax.random.PRNGKey(0)), sspecs)
             jitted = jax.jit(
                 step8,
-                in_shardings=(named(train_state_specs(cfg, opt, mesh)),
-                              named(batch_specs(cfg, mesh, B))),
+                in_shardings=(sspecs, named(batch_specs(cfg, mesh, B))),
             )
             losses8, us8 = _timed_steps(jitted, state, batches)
         results["fidelity_8way"] = {

@@ -1,16 +1,10 @@
 """Shared kernel utilities."""
 from __future__ import annotations
 
+import re
+
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-compat shim: pltpu.CompilerParams (new name) falls back to
-    pltpu.TPUCompilerParams (pre-0.5 name). All three kernel families route
-    through this instead of touching the pltpu attribute directly."""
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+from jax.extend import core as jex_core
 
 
 def pick_block(dim: int, pref: int, granule: int = 128) -> int:
@@ -33,22 +27,44 @@ def pick_block(dim: int, pref: int, granule: int = 128) -> int:
     return dim
 
 
-def _walk_pallas_inputs(jaxpr, out):
-    """Collect the input avals of every ``pallas_call`` in ``jaxpr``,
-    recursing through call/control-flow sub-jaxprs but NOT into the pallas
-    kernels themselves (the boundary is what we audit)."""
+def _walk_pallas_calls(jaxpr, out):
+    """Collect every ``pallas_call`` equation in ``jaxpr``, recursing through
+    call/control-flow sub-jaxprs but NOT into the pallas kernels themselves
+    (the boundary is what we audit)."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            out.extend(v.aval for v in eqn.invars)
+            out.append(eqn)
             continue
         for val in eqn.params.values():
             vals = val if isinstance(val, (list, tuple)) else (val,)
             for v in vals:
-                if isinstance(v, jax.core.ClosedJaxpr):
-                    _walk_pallas_inputs(v.jaxpr, out)
-                elif isinstance(v, jax.core.Jaxpr):
-                    _walk_pallas_inputs(v, out)
+                if isinstance(v, jex_core.ClosedJaxpr):
+                    _walk_pallas_calls(v.jaxpr, out)
+                elif isinstance(v, jex_core.Jaxpr):
+                    _walk_pallas_calls(v, out)
     return out
+
+
+def pallas_calls(closed_jaxpr) -> list[tuple[str, bool]]:
+    """``(kernel name, interpret flag)`` of every ``pallas_call`` a traced
+    program contains (e.g. ``jax.jit(f).trace(*args).jaxpr``)."""
+    return [(str(e.params["name"]), bool(e.params["interpret"]))
+            for e in _walk_pallas_calls(closed_jaxpr.jaxpr, [])]
+
+
+def tpu_kernels_in_hlo(hlo_text: str) -> dict[str, int]:
+    """Count the ``tpu_custom_call`` instructions of a compiled TPU program
+    (``compiled.as_text()``) per repo kernel name (``panther_*``). A kernel
+    run in interpret mode lowers to plain HLO and is not counted, so this is
+    the proof that the Mosaic kernel itself is in the program."""
+    counts: dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = re.search(r"panther_[a-z_]*[a-z]", line)
+        if m:
+            counts[m.group(0)] = counts.get(m.group(0), 0) + 1
+    return counts
 
 
 def pallas_input_avals(fn, *args, **kwargs):
@@ -57,7 +73,7 @@ def pallas_input_avals(fn, *args, **kwargs):
     behind the no-quantized-operand-crosses-HBM contract of the fused
     DAC/RNG boundary."""
     jaxpr = jax.make_jaxpr(fn)(*args, **kwargs)
-    return _walk_pallas_inputs(jaxpr.jaxpr, [])
+    return [v.aval for e in _walk_pallas_calls(jaxpr.jaxpr, []) for v in e.invars]
 
 
 def forbid_pallas_inputs(fn, *args, forbidden, **kwargs):

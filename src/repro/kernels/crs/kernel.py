@@ -16,7 +16,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.slicing import LOGICAL_BITS, RADIX, SliceSpec
-from repro.kernels.common import pick_block, tpu_compiler_params
+from repro.kernels.common import pick_block
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -86,7 +86,7 @@ def crs(
         out_specs=pl.BlockSpec((S, bm, bn), lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct(planes.shape, jnp.int8),
         input_output_aliases={0: 0},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -38,10 +38,11 @@ inside the kernel. The float activation block is the only operand that
 crosses HBM; the tile prologue (``_dac_block``) performs the ``io_bits``
 round/saturate onto the ``2^-frac_bits`` grid — the exact arithmetic of
 ``core.fixed_point.quantize``, with the scale built by the same ``exp2i``
-bitcast so fused and unfused integer grids are bit-identical — and the
-bit-plane extraction happens per tile in VMEM. ``frac_bits`` enters as a
-scalar through SMEM. No ``x_q``-shaped or ``[T, B, M]`` plane array exists
-at the pallas_call boundary (jaxpr-audited by
+bitcast (outside the kernel) so fused and unfused integer grids are
+bit-identical — and the bit-plane extraction happens per tile in VMEM. The
+scale ``2^frac_bits`` enters as an f32 scalar through SMEM. No
+``x_q``-shaped or ``[T, B, M]`` plane array exists at the pallas_call
+boundary (jaxpr-audited by
 ``kernels.common.forbid_pallas_inputs`` in tests and the bench gate).
 
 **Double-buffered tile DMA** (``double_buffer=True``, the default fused
@@ -83,20 +84,21 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.fixed_point import exp2i
 from repro.core.mvm import _adc
 from repro.core.slicing import LOGICAL_BITS, SliceSpec
-from repro.kernels.common import pick_block, tpu_compiler_params
+from repro.kernels.common import pick_block
 
 XBAR_ROWS = 128
 DEFAULT_BB = 8
 DEFAULT_BN = 256
 
 
-def _dac_block(x, frac_bits, io_bits: int):
+def _dac_block(x, scale, io_bits: int):
     """In-kernel DAC prologue: float block -> int32 on the ``2^-frac_bits``
     grid, saturated to ``io_bits`` signed — the exact arithmetic of
-    ``core.fixed_point.quantize`` (``exp2i`` is a pure bitcast, so the scale
-    is the identical power of two in-kernel and out)."""
+    ``core.fixed_point.quantize``. ``scale`` is the f32 ``exp2i(frac_bits)``
+    computed outside the kernel and read from SMEM: the identical power of
+    two as the unfused path (Mosaic cannot bitcast an SMEM scalar)."""
     lim = float(2 ** (io_bits - 1) - 1)
-    y = jnp.round(x.astype(jnp.float32) * exp2i(frac_bits))
+    y = jnp.round(x.astype(jnp.float32) * scale)
     return jnp.clip(y, -lim, lim).astype(jnp.int32)
 
 
@@ -149,9 +151,13 @@ def _tile_compute(xq, w, *, spec: SliceSpec, io_bits: int, adc_bits: int | None,
 
     noisy = dev is not None and dev.read_noise > 0.0
     if adc_bits is None:
-        # ideal ADC: bit-streaming is exact -> contract the full input once
+        # ideal ADC: bit-streaming is exact -> contract the full input once.
+        # xq carries up to io_bits-1 = 15 magnitude bits, more than one bf16
+        # pass holds: HIGHEST (f32 contraction) keeps every product exact.
         z = jax.lax.dot_general(
-            xq.astype(jnp.float32), w_cat, dims, preferred_element_type=jnp.float32
+            xq.astype(jnp.float32), w_cat, dims,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         )  # [bb, S*bn]
         if noisy:
             # each of the io_bits-1 bit cycles reads the same frozen channel
@@ -167,8 +173,12 @@ def _tile_compute(xq, w, *, spec: SliceSpec, io_bits: int, adc_bits: int | None,
         xp = jnp.concatenate(
             [((mx >> t) & 1) * sx for t in range(mag_bits)], axis=0
         ).astype(jnp.float32)
+        # bit planes (0/±1) and digits (|d| <= plane_max <= 128) are exact in
+        # bf16 and every column sum (<= 128 * 128) is exact in the f32
+        # accumulator: one bf16 MXU pass computes the exact currents
         y = jax.lax.dot_general(
-            xp, w_cat, dims, preferred_element_type=jnp.float32
+            xp.astype(jnp.bfloat16), w_cat.astype(jnp.bfloat16), dims,
+            preferred_element_type=jnp.float32,
         )  # [(io_bits-1)*bb, S*bn] — every (bit, slice) column current at once
         if noisy:
             # per-ADC-channel offset on the raw column current, pre-ADC
@@ -271,7 +281,7 @@ def mvm_sliced(
         out_specs=pl.BlockSpec((bb, bn), lambda i, j, k: (i, j)),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((B, out_dim), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -279,7 +289,7 @@ def mvm_sliced(
     )(x_q, planes)
 
 
-def _mvm_fused_kernel(f_ref, x_ref, planes_ref, *rest, spec,
+def _mvm_fused_kernel(scale_ref, x_ref, planes_ref, *rest, spec,
                       io_bits, adc_bits, nk, transpose, dev=None):
     rest = list(rest)
     off_ref = None
@@ -295,7 +305,7 @@ def _mvm_fused_kernel(f_ref, x_ref, planes_ref, *rest, spec,
 
     # DAC quantize fused into the tile prologue: the float activation block
     # is the only operand that crossed HBM.
-    xq = _dac_block(x_ref[...], f_ref[0, 0], io_bits)
+    xq = _dac_block(x_ref[...], scale_ref[0, 0], io_bits)
     acc_ref[...] += _tile_compute(
         xq, planes_ref[...],
         spec=spec, io_bits=io_bits, adc_bits=adc_bits, transpose=transpose,
@@ -309,7 +319,7 @@ def _mvm_fused_kernel(f_ref, x_ref, planes_ref, *rest, spec,
         out_ref[...] = acc_ref[...]
 
 
-def _mvm_fused_db_kernel(f_ref, x_ref, planes_ref, *rest,
+def _mvm_fused_db_kernel(scale_ref, x_ref, planes_ref, *rest,
                          spec, io_bits, adc_bits, nk, bn, transpose, dev=None):
     """Double-buffered lowering: 2-D grid (batch, out) — the crossbar-tile
     loop runs *inside* the kernel over the full input strip, with the next
@@ -321,9 +331,7 @@ def _mvm_fused_db_kernel(f_ref, x_ref, planes_ref, *rest,
         off_ref = rest.pop(0)
     out_ref, wtile_ref, sem = rest
     j = pl.program_id(1)  # program ids must be read at kernel top level
-    # whole strip quantized once per block (bb x contract int32 in VMEM)
-    xq = _dac_block(x_ref[...], f_ref[0, 0], io_bits)
-    bb = xq.shape[0]
+    scale = scale_ref[0, 0]
 
     def tile_copy(slot, kk):
         # identical descriptor for start and wait (same src/dst/sem triplet)
@@ -343,7 +351,10 @@ def _mvm_fused_db_kernel(f_ref, x_ref, planes_ref, *rest,
             tile_copy(jax.lax.rem(k + 1, 2), k + 1).start()
 
         tile_copy(slot, k).wait()
-        xq_k = jax.lax.dynamic_slice(xq, (0, k * XBAR_ROWS), (bb, XBAR_ROWS))
+        # this tile's strip columns, read through the ref (Mosaic has no
+        # dynamic_slice of a value) and DAC-quantized in the prologue
+        col = pl.multiple_of(k * XBAR_ROWS, XBAR_ROWS)
+        xq_k = _dac_block(x_ref[:, pl.ds(col, XBAR_ROWS)], scale, io_bits)
         return acc + _tile_compute(
             xq_k, wtile_ref[slot],
             spec=spec, io_bits=io_bits, adc_bits=adc_bits, transpose=transpose,
@@ -414,7 +425,7 @@ def mvm_sliced_fused(
         (1, 1), (lambda i, j: (0, 0)) if double_buffer else (lambda i, j, k: (0, 0)),
         memory_space=pltpu.SMEM,
     )
-    f_arg = jnp.asarray(frac_bits, jnp.int32).reshape(1, 1)
+    f_arg = exp2i(frac_bits).reshape(1, 1)  # f32 DAC scale 2^frac_bits
     extra_specs, extra_args = [], []
     if noisy:
         off_spec = pl.BlockSpec(
@@ -442,7 +453,7 @@ def mvm_sliced_fused(
             in_specs=[
                 f_spec,
                 pl.BlockSpec((bb, contract), lambda i, j: (i, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),  # full planes, DMA'd per tile
+                pl.BlockSpec(memory_space=pl.ANY),  # full planes, DMA'd per tile
                 *extra_specs,
             ],
             out_specs=pl.BlockSpec((bb, bn), lambda i, j: (i, j)),
@@ -451,7 +462,7 @@ def mvm_sliced_fused(
                 pltpu.SemaphoreType.DMA((2,)),
             ],
             out_shape=jax.ShapeDtypeStruct((B, out_dim), jnp.float32),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
             ),
             interpret=interpret,
@@ -477,7 +488,7 @@ def mvm_sliced_fused(
         out_specs=pl.BlockSpec((bb, bn), lambda i, j, k: (i, j)),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((B, out_dim), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

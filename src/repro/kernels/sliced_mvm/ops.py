@@ -205,7 +205,6 @@ def mvm_sliced_batched(
     t = x2.shape[0]
     pad = (-t) % BATCH_GRANULE
     if pad:
-        # jnp.pad, not concatenate — see the note in mvm_sliced_sharded
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     out = mvm_sliced(
         planes, x2, spec, io_bits=io_bits, adc_bits=adc_bits, transpose=transpose,
@@ -301,16 +300,11 @@ def mvm_sliced_sharded(
             transpose=transpose, use_kernel=use_kernel, interpret=interpret,
         )
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     x2 = x_q.reshape(-1, contract)
     t = x2.shape[0]
-    # pad so every data shard lands on the kernel's token granule. jnp.pad,
-    # NOT concatenate: on jax 0.4.37 a concatenate feeding a shard_map input
-    # under jit mispartitions and the reshard SUMS over 'model' instead of
-    # gathering (minimal repro in tests/test_distributed.py history; pad and
-    # at[].set lower correctly).
+    # pad so every data shard lands on the kernel's token granule
     pad = (-t) % (BATCH_GRANULE * dsize)
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
@@ -352,7 +346,7 @@ def mvm_sliced_sharded(
     # the DAC exponent rides along replicated (P()); a dummy zero keeps the
     # shard_map signature static on the unfused path
     f_arg = jnp.asarray(0 if frac_bits is None else frac_bits, jnp.int32)
-    out = shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -361,7 +355,7 @@ def mvm_sliced_sharded(
             P(),
         ),
         out_specs=P(dp_entry, maxis if out_sharded else None),
-        check_rep=False,
+        check_vma=False,
     )(planes, x2, f_arg)
     if pad:
         out = out[:t]

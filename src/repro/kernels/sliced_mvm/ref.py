@@ -59,6 +59,10 @@ from repro.core.slicing import LOGICAL_BITS, SliceSpec
 from repro.kernels.sliced_mvm.kernel import READ_SALT, READ_SALT_T
 
 XBAR_ROWS = 128
+# every contraction here runs in f32 (HIGHEST): on a TPU the default is one
+# bf16 pass, which would round the int16 DAC codes and the 1/step-prescaled
+# planes — the kernels' exact integer sums would then not be matched
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def read_offsets_ref(device, spec: SliceSpec, gtile, col0, n_cols: int,
@@ -125,8 +129,8 @@ def mvm_sliced_ref(
         for tile in range(n_tiles):
             lo, hi = tile * xbar_rows, min((tile + 1) * xbar_rows, M)
             y = jnp.einsum("bm,smn->bsn", xf[:, lo:hi], w[:, lo:hi],
-                           preferred_element_type=jnp.float32)
-            out = out + jnp.einsum("bsn,s->bn", y, s_scale)
+                           precision=HIGHEST, preferred_element_type=jnp.float32)
+            out = out + jnp.einsum("bsn,s->bn", y, s_scale, precision=HIGHEST)
         return out
 
     bp = bit_planes(x_q, io_bits).astype(jnp.float32)  # [T, B, M], extracted once
@@ -134,9 +138,9 @@ def mvm_sliced_ref(
     for tile in range(n_tiles):
         lo, hi = tile * xbar_rows, min((tile + 1) * xbar_rows, M)
         y = jnp.einsum("tbm,smn->tbsn", bp[:, :, lo:hi], w[:, lo:hi],
-                       preferred_element_type=jnp.float32)
+                       precision=HIGHEST, preferred_element_type=jnp.float32)
         y = _adc(y, full_scale[:, None], adc_bits)
-        out = out + jnp.einsum("tbsn,ts->bn", y, scales)
+        out = out + jnp.einsum("tbsn,ts->bn", y, scales, precision=HIGHEST)
     return out
 
 
@@ -192,10 +196,10 @@ def mvm_sliced_fused_ref(
         for tile in range(n_tiles):
             lo, hi = tile * xbar_rows, min((tile + 1) * xbar_rows, M)
             y = jnp.einsum("bm,smn->bsn", xf[:, lo:hi], w[:, lo:hi],
-                           preferred_element_type=jnp.float32)
+                           precision=HIGHEST, preferred_element_type=jnp.float32)
             if noisy:
                 y = y + offs(tile)[None] * float(2 ** (io_bits - 1) - 1)
-            out = out + jnp.einsum("bsn,s->bn", y, s_scale)
+            out = out + jnp.einsum("bsn,s->bn", y, s_scale, precision=HIGHEST)
         return out
 
     T = io_bits - 1
@@ -212,13 +216,13 @@ def mvm_sliced_fused_ref(
     for tile in range(n_tiles):
         lo, hi = tile * xbar_rows, min((tile + 1) * xbar_rows, M)
         y = jnp.einsum("tbm,smn->tbsn", bp[:, :, lo:hi], w2[:, lo:hi],
-                       preferred_element_type=jnp.float32)
+                       precision=HIGHEST, preferred_element_type=jnp.float32)
         if noisy:
             # channel offset on the raw current, pre-round (prescaled grid)
             y = y + (offs(tile) * inv_step)[None, None]
         q = jnp.clip(jnp.round(y), -half, half)  # integer ADC codes
-        z = jnp.tensordot(tw, q, axes=([0], [0]))  # bit fold -> [B, S, n]
-        out = out + jnp.einsum("bsn,s->bn", z, sw)  # slice fold (step folded)
+        z = jnp.tensordot(tw, q, axes=([0], [0]), precision=HIGHEST)  # bit fold -> [B, S, n]
+        out = out + jnp.einsum("bsn,s->bn", z, sw, precision=HIGHEST)  # slice fold (step folded)
     return out
 
 

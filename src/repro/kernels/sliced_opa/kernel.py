@@ -57,7 +57,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.slicing import LOGICAL_BITS, SliceSpec
-from repro.kernels.common import pick_block, tpu_compiler_params
+from repro.kernels.common import pick_block
 
 _RADIX_MASK = (1 << LOGICAL_BITS) - 1  # 15
 _HALF = 1 << (LOGICAL_BITS - 1)  # 8
@@ -111,7 +111,7 @@ def opa_deposit(
         out_specs=pl.BlockSpec((S, bm, bn), lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct(planes.shape, jnp.int8),
         input_output_aliases={1: 0},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
@@ -199,18 +199,24 @@ def _opa_fused_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # MXU contraction over this token tile: [bm, bt] x [bt, bn].
+    # MXU contraction over this token tile: [bm, bt] x [bt, bn]. HIGHEST
+    # (f32 contraction), as in the references: a single bf16 pass would
+    # round the f32 operands before the outer product.
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...],
         dh_ref[...],
         (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
     @pl.when(k == nk - 1)
     def _finalize():
         lim = float(2**31 - 1)
-        y = acc_ref[...] * scale_ref[0, 0]
+        # (acc * scale) * 2^F: the rounding of the dense path's
+        # quantize(-lr * g, F); the second factor is an exact power of two,
+        # so a fused multiply-add into the rounding below cannot change y
+        y = acc_ref[...] * scale_ref[0, 0] * scale_ref[0, 1]
         if dev is not None and (dev.asym_up != 1.0 or dev.asym_down != 1.0):
             # asymmetric potentiation/depression: gain depends on the sign of
             # the analog increment, before it quantizes to the grid
@@ -268,11 +274,15 @@ def opa_fused(
     rng_impl: str = "counter",
     dev=None,
     dkey: jax.Array | None = None,
+    grid_scale: jax.Array | float = 1.0,
 ) -> jax.Array:
-    """Fused ``planes <- deposit(planes, q(X^T dH * scale))``.
+    """Fused ``planes <- deposit(planes, q(X^T dH * scale * grid_scale))``.
 
     planes int8 [S,M,N]; x [T,M]; dh [T,N] (``-lr`` folded by caller into
-    ``scale``); scale f32 scalar (±lr·2**F). Stochastic rounding options:
+    ``scale``); scale f32 scalar (±lr, or ±lr·2**F with ``grid_scale=1``);
+    ``grid_scale`` f32 power of two ``2**F`` applied after ``scale`` — the
+    same two roundings as ``quantize(-lr * g, F)``. Stochastic rounding
+    options:
 
     * ``rkey`` int32 ``[2]`` key words — the noise is generated **inside the
       kernel** at global element coordinates (``rng_impl="counter"``, the
@@ -302,13 +312,14 @@ def opa_fused(
     nk = T // bt
     grid = (M // bm, N // bn, nk)
     in_specs = [
-        pl.BlockSpec((1, 1), lambda i, j, k: (0, 0), memory_space=pltpu.SMEM),
+        pl.BlockSpec((1, 2), lambda i, j, k: (0, 0), memory_space=pltpu.SMEM),
         pl.BlockSpec((bt, bm), lambda i, j, k: (k, i)),
         pl.BlockSpec((bt, bn), lambda i, j, k: (k, j)),
         pl.BlockSpec((S, bm, bn), lambda i, j, k: (0, i, j)),
     ]
     args = [
-        jnp.asarray(scale, jnp.float32).reshape(1, 1),
+        jnp.stack([jnp.asarray(scale, jnp.float32),
+                   jnp.asarray(grid_scale, jnp.float32)]).reshape(1, 2),
         x.astype(jnp.float32),
         dh.astype(jnp.float32),
         planes,
@@ -335,7 +346,7 @@ def opa_fused(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct(planes.shape, jnp.int8),
         input_output_aliases={3: 0},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

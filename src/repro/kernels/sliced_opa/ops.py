@@ -68,8 +68,8 @@ def opa_device_update(planes, g, lr, frac_bits, spec: SliceSpec, *, device,
 
     if device.write_noise > 0.0 and key is None:
         raise ValueError("DeviceModel.write_noise requires a PRNG key")
-    scale = -jnp.asarray(lr, jnp.float32) * exp2i(frac_bits)
-    upd = _ref.write_device(g.astype(jnp.float32) * scale, device,
+    y = g.astype(jnp.float32) * -jnp.asarray(lr, jnp.float32) * exp2i(frac_bits)
+    upd = _ref.write_device(y, device,
                             key=key, stochastic=stochastic, rng_mode=rng_mode)
     new = opa_deposit(planes, upd, spec, use_kernel=use_kernel, interpret=interpret)
     if device.stuck_frac > 0.0:
@@ -163,7 +163,7 @@ def opa_fused_update(
     # pipeline's quantize() uses, or the fused/dense bit-compat breaks
     from repro.core.fixed_point import WRITE_NOISE_FOLD, counter_key_scalars, exp2i
 
-    scale = -jnp.asarray(lr, jnp.float32) * exp2i(frac_bits)
+    scale, grid = -jnp.asarray(lr, jnp.float32), exp2i(frac_bits)
     noise = rkey = None
     if stochastic and rng_mode == "grid":
         noise = jax.random.uniform(key, planes.shape[1:], jnp.float32)
@@ -179,6 +179,7 @@ def opa_fused_update(
             planes, x, dh, scale, spec=spec, interpret=interpret,
             noise=noise, rkey=rkey, rng_impl=rng_impl, dev=device,
             dkey=None if dk_base is None else counter_key_scalars(dk_base),
+            grid_scale=grid,
         )
 
     # stacked leaf [S, *stack, M, N]: one kernel launch per stacked layer
@@ -210,7 +211,7 @@ def opa_fused_update(
         return None, _k.opa_fused(
             a["p"], a["x"], a["dh"], scale, spec=spec, interpret=interpret,
             noise=a.get("n"), rkey=a.get("k"), rng_impl=rng_impl,
-            dev=device, dkey=a.get("dk"),
+            dev=device, dkey=a.get("dk"), grid_scale=grid,
         )
 
     _, out = jax.lax.scan(body, None, xs)

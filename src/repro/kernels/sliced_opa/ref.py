@@ -76,7 +76,8 @@ def opa_fused_update_ref(planes, x, dh, lr, frac_bits, spec: SliceSpec, *,
     """Operand-form OPA update oracle: exact mirror of the dense pipeline.
 
     ``einsum(x, dh)`` in the operand dtype is the same contraction XLA's AD
-    emits for ``x @ w`` on the dense-grad path, and ``quantize`` is the same
+    emits for ``x @ w`` on the dense-grad path (at the kernel's HIGHEST
+    precision, so f32 operands are not rounded to one bf16 pass on a TPU), and ``quantize`` is the same
     call ``optim.panther`` makes there — so this oracle (and the CPU
     dispatch of ``opa_fused_update``) is bit-identical to dense-grad +
     ``opa_deposit``, including the stochastic-rounding draw for a given
@@ -85,15 +86,14 @@ def opa_fused_update_ref(planes, x, dh, lr, frac_bits, spec: SliceSpec, *,
     (already normalized: None unless some write-path field is non-ideal)
     reroutes through the device-physics mirror of the kernel finalize.
     """
-    g = jnp.einsum("...tm,...tn->...mn", x, dh)
+    g = jnp.einsum("...tm,...tn->...mn", x, dh, precision=jax.lax.Precision.HIGHEST)
     if device is None:
         upd = quantize(-lr * g.astype(jnp.float32), frac_bits,
                        stochastic=stochastic, key=key, rng_mode=rng_mode)
         return opa_batched(planes, upd, spec)
-    # scale composed as the kernel does (-lr * 2^F): exactly equal to
-    # quantize's (-lr*g) * 2^F because the 2^F factor is exponent-only
-    scale = -jnp.asarray(lr, jnp.float32) * exp2i(frac_bits)
-    upd = write_device(g.astype(jnp.float32) * scale, device,
+    # (g * -lr) * 2^F, as the kernel and quantize() round it
+    y = g.astype(jnp.float32) * -jnp.asarray(lr, jnp.float32) * exp2i(frac_bits)
+    upd = write_device(y, device,
                        key=key, stochastic=stochastic, rng_mode=rng_mode)
     new = opa_batched(planes, upd, spec)
     if device.stuck_frac > 0.0:
@@ -110,7 +110,8 @@ def opa_fused_ref(planes, x, dh, scale, spec: SliceSpec, *, device=None,
     ``device``/``dkey`` mirror the kernel's raw entry (``dkey`` int32 [2]
     write-noise key words, matching the kernel's SMEM prefetch).
     """
-    acc = jnp.einsum("tm,tn->mn", x.astype(jnp.float32), dh.astype(jnp.float32))
+    acc = jnp.einsum("tm,tn->mn", x.astype(jnp.float32), dh.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
     lim = float(2**31 - 1)
     y = acc * jnp.asarray(scale, jnp.float32)
     if device is not None:

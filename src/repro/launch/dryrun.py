@@ -33,7 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models import lm
 from repro.optim import PantherConfig
 from repro.optim.schedules import constant
@@ -197,14 +197,14 @@ def build_cell(arch: str, shape_name: str, mesh):
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str, tp: int | None = None) -> dict:
     if tp is not None and mesh_kind == "single":
-        mesh = jax.make_mesh((256 // tp, tp), ("data", "model"))
+        mesh = make_mesh((256 // tp, tp), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     rec = {"arch": arch, "shape": shape_name, "mesh": mesh_kind, "n_devices": mesh.size,
            "tp": mesh.shape["model"], "kv_dtype": str(KV_DTYPE.__name__)}
     build_cell.last_knobs = {}
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted, args = build_cell(arch, shape_name, mesh)
         lowered = jitted.lower(*args)
         rec["lower_s"] = round(time.time() - t0, 2)

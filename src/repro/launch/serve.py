@@ -258,6 +258,9 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.trace:
         run_trace_bench(args)
     else:

@@ -39,15 +39,16 @@ def main():
         os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 
     import jax
-    import jax.numpy as jnp
 
     from repro import configs
     from repro.checkpoint import CheckpointManager
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.data import SyntheticLMDataset
     from repro.optim import PantherConfig
     from repro.optim.schedules import constant, cosine, wsd
     from repro.train.step import TrainState, make_train_step, train_state_init
 
+    enable_compile_cache()
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     sched = {
         "constant": lambda: constant(args.lr),
@@ -78,7 +79,9 @@ def main():
     step_fn = make_train_step(cfg, opt_cfg, sched, mesh=mesh,
                               global_batch=args.batch if mesh else None,
                               plan_rules=rules)
-    state = train_state_init(cfg, opt_cfg, jax.random.PRNGKey(0))
+    # jitted: op by op, slicing a large embedding keeps every int32
+    # temporary alive at once
+    state = jax.jit(lambda: train_state_init(cfg, opt_cfg, jax.random.PRNGKey(0)))()
 
     ckpt = CheckpointManager(args.ckpt_dir, every=args.ckpt_every) if args.ckpt_dir else None
     start = 0

@@ -57,7 +57,6 @@ from repro.core import (
     DEFAULT_SPEC,
     SliceSpec,
     choose_frac_bits,
-    crs as crs_fn,
     dequantize_planes,
     saturation_fraction,
     slice_weights,
@@ -92,9 +91,10 @@ class PantherConfig:
     # U[0,1) HBM grid — old checkpoints replay bit-identically), "hw" (TPU
     # hardware PRNG in-kernel; fastest, not replayable off-TPU).
     rng_mode: str = "counter"
-    # OPA kernel dispatch override (None = auto: Pallas on TPU, jnp ref on
-    # CPU). Tests force (True, True) to run the fused kernel in interpret
-    # mode; the ref path is bit-identical to dense-grad + opa_deposit.
+    # OPA / CRS kernel dispatch override (None = auto: Pallas on TPU, jnp ref
+    # on CPU and under a mesh). Tests force (True, True) to run the kernels
+    # in interpret mode; the ref path is bit-identical to dense-grad +
+    # opa_deposit.
     opa_use_kernel: bool | None = None
     opa_interpret: bool | None = None
 
@@ -137,11 +137,8 @@ def tiki_taka(cfg: PantherConfig = PantherConfig(), beta: float = 0.875) -> Pant
     return dataclasses.replace(cfg, momentum=beta, variant="tiki-taka")
 
 
-def _crs_dispatch(planes, spec):
-    """CRS via the Pallas kernel on TPU (rank-3 planes), jnp ref otherwise."""
-    if planes.ndim == 3 and jax.default_backend() == "tpu":
-        return crs_op(planes, spec)
-    return crs_fn(planes, spec)
+def _crs(planes, spec, cfg: PantherConfig):
+    return crs_op(planes, spec, use_kernel=cfg.opa_use_kernel, interpret=cfg.opa_interpret)
 
 
 def _default_plan(params, cfg: PantherConfig):
@@ -438,7 +435,7 @@ def update(
                 use_kernel=cfg.opa_use_kernel, interpret=cfg.opa_interpret,
             )
         planes = jax.lax.cond(
-            do_crs, lambda x, _s=spec: _crs_dispatch(x, _s), lambda x: x, planes
+            do_crs, lambda x, _s=spec: _crs(x, _s, cfg), lambda x: x, planes
         )
         new_sliced = SlicedTensor(planes=planes, frac_bits=s.frac_bits)
         new_s.append(new_sliced)
@@ -556,7 +553,7 @@ def update_split(grads, digital, sliced, step, lr, cfg: PantherConfig = PantherC
                 use_kernel=cfg.opa_use_kernel, interpret=cfg.opa_interpret,
             )
         planes = jax.lax.cond(
-            do_crs, lambda x, _s=spec: _crs_dispatch(x, _s), lambda x: x, planes
+            do_crs, lambda x, _s=spec: _crs(x, _s, cfg), lambda x: x, planes
         )
         new_d.append(None)
         new_s.append(SlicedTensor(planes=planes, frac_bits=s.frac_bits))

@@ -30,6 +30,7 @@ per leaf.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 from typing import Any, NamedTuple
 
 import jax
@@ -246,6 +247,11 @@ def make_train_step(
         raise ValueError("stash_fallback only augments the default rules; "
                          "append repro.plan.operand_stash_rule() to your "
                          "plan_rules (or resolve it into your plan) directly")
+    if mesh is not None and opt_cfg.opa_use_kernel is None:
+        # Mosaic cannot partition a pallas_call and the optimizer kernels
+        # (fused OPA, deposit, CRS) are not wrapped in a shard_map yet: under
+        # a mesh the update runs their SPMD-partitionable jnp references
+        opt_cfg = dataclasses.replace(opt_cfg, opa_use_kernel=False)
     if fidelity is not None and fidelity.spec != opt_cfg.spec:
         raise ValueError(
             f"FidelityConfig.spec {fidelity.spec} must match the optimizer "

@@ -229,13 +229,14 @@ def test_sharded_device_read_matches_single_host():
             from repro.core import DEFAULT_SPEC, slice_weights
             from repro.core.fixed_point import choose_frac_bits
             from repro.kernels.sliced_mvm import mvm_sliced_fused_batched, mvm_sliced_sharded
+            from repro.launch.mesh import make_mesh
             from repro.models.common import DeviceModel
             dev = DeviceModel(read_noise=0.02)
             rng = np.random.default_rng(0)
             M = N = 512  # 4-way model shards hold exactly one 128-row tile each
             q = jnp.asarray(rng.integers(-256, 257, size=(M, N)), jnp.int32)
             planes = slice_weights(q, DEFAULT_SPEC)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             for transpose in (False, True):
                 contract = N if transpose else M
                 x = jnp.asarray(rng.normal(size=(3, 5, contract)), jnp.float32)

@@ -77,21 +77,22 @@ def test_train_step_runs_on_mesh():
         from repro.optim import PantherConfig
         from repro.optim.schedules import constant
         from repro.plan import default_rules
+        from repro.launch.mesh import make_mesh
         from repro.train.step import (batch_specs, make_train_step,
                                       train_state_init, train_state_specs)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_smoke("gemma_2b")
         opt = PantherConfig(stochastic_round=False)
         B, S = 4, 32
         step = make_train_step(cfg, opt, constant(1e-2), mesh=mesh, global_batch=B, fsdp=True)
         named = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                        is_leaf=lambda x: isinstance(x, P))
-        with mesh:
-            state = train_state_init(cfg, opt, jax.random.PRNGKey(0))
-            jitted = jax.jit(step, in_shardings=(named(train_state_specs(cfg, opt, mesh, fsdp=True)),
-                                                 named(batch_specs(cfg, mesh, B))),
+        sspecs = named(train_state_specs(cfg, opt, mesh, fsdp=True))
+        batch = {"inputs": jnp.ones((B, S), jnp.int32), "labels": jnp.ones((B, S), jnp.int32)}
+        with jax.set_mesh(mesh):
+            state = jax.device_put(train_state_init(cfg, opt, jax.random.PRNGKey(0)), sspecs)
+            jitted = jax.jit(step, in_shardings=(sspecs, named(batch_specs(cfg, mesh, B))),
                              donate_argnums=0)
-            batch = {"inputs": jnp.ones((B, S), jnp.int32), "labels": jnp.ones((B, S), jnp.int32)}
             state, m = jitted(state, batch)
             state, m = jitted(state, batch)
         import math
@@ -108,6 +109,7 @@ def test_sharded_loss_matches_single_device():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke
         from repro.distributed import sharding as shd
+        from repro.launch.mesh import make_mesh
         from repro.models import lm
         cfg = get_smoke("granite_moe_1b_a400m")
         params = lm.init_params(cfg, jax.random.PRNGKey(0))
@@ -115,10 +117,10 @@ def test_sharded_loss_matches_single_device():
         batch = {"inputs": jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab),
                  "labels": jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, cfg.vocab)}
         ref = float(lm.loss_fn(cfg, params, batch, remat=False))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         pspecs = jax.tree.map(lambda s: NamedSharding(mesh, s), shd.param_specs(params, mesh=mesh),
                               is_leaf=lambda x: isinstance(x, P))
-        with mesh:
+        with jax.set_mesh(mesh):
             f = jax.jit(lambda p, b: lm.loss_fn(cfg, p, b, remat=False), in_shardings=(pspecs, None))
             got = float(f(params, batch))
         assert abs(got - ref) < 5e-3 * (1 + abs(ref)), (got, ref)
@@ -180,13 +182,14 @@ def test_fidelity_mesh_step_builds():
     import dataclasses
     import jax, jax.numpy as jnp
     from repro.configs import fidelity_presets, get_smoke
+    from repro.launch.mesh import make_mesh
     from repro.optim import PantherConfig
     from repro.optim.schedules import constant
     from repro.plan import default_rules
     from repro.train.step import make_train_step
 
     cfg = dataclasses.replace(get_smoke("gemma_2b"), dtype=jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     opt = PantherConfig(stochastic_round=False)
     step = make_train_step(cfg, opt, constant(0.1), mesh=mesh, global_batch=4,
                            plan_rules=default_rules(
@@ -204,11 +207,12 @@ def test_sharded_fidelity_read_matches_single_host():
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import DEFAULT_SPEC, slice_weights
         from repro.kernels.sliced_mvm import mvm_sliced_batched, mvm_sliced_sharded
+        from repro.launch.mesh import make_mesh
         rng = np.random.default_rng(0)
         M = N = 512  # 4-way model shards hold exactly one 128-row tile each
         q = jnp.asarray(rng.integers(-256, 257, size=(M, N)), jnp.int32)
         planes = slice_weights(q, DEFAULT_SPEC)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         for transpose in (False, True):
             contract = N if transpose else M
             x = jnp.asarray(rng.integers(-100, 101, size=(3, 5, contract)), jnp.int32)
@@ -239,6 +243,7 @@ def test_sharded_fidelity_train_step_matches_single_host():
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import fidelity_presets, get_smoke
+        from repro.launch.mesh import make_mesh
         from repro.optim import PantherConfig
         from repro.optim.schedules import constant
         from repro.plan import default_rules
@@ -255,30 +260,29 @@ def test_sharded_fidelity_train_step_matches_single_host():
                                          plan_rules=default_rules(opt, fidelity=fid)))
         s1, ma = step1(s0, batch)
         s1, mb = step1(s1, batch)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         named = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                        is_leaf=lambda x: isinstance(x, P))
-        with mesh:
-            st = train_state_init(cfg, opt, jax.random.PRNGKey(0))
+        sspecs = named(train_state_specs(cfg, opt, mesh))
+        with jax.set_mesh(mesh):
+            st = jax.device_put(train_state_init(cfg, opt, jax.random.PRNGKey(0)), sspecs)
             jitted = jax.jit(
                 make_train_step(cfg, opt, constant(0.3), mesh=mesh, global_batch=B,
                                 plan_rules=default_rules(opt, fidelity=fid)),
-                in_shardings=(named(train_state_specs(cfg, opt, mesh)),
-                              named(batch_specs(cfg, mesh, B))))
+                in_shardings=(sspecs, named(batch_specs(cfg, mesh, B))))
             st, na = jitted(st, batch)
             st, nb = jitted(st, batch)
         for m, n, tol in ((ma, na, 1e-3), (mb, nb, 5e-3)):
             d = abs(float(m["loss"]) - float(n["loss"]))
             assert d < tol * (1 + abs(float(m["loss"]))), (d, float(m["loss"]), float(n["loss"]))
         # finite ADC: runs sharded end to end, planes update
-        with mesh:
-            st = train_state_init(cfg, opt, jax.random.PRNGKey(0))
+        with jax.set_mesh(mesh):
+            st = jax.device_put(train_state_init(cfg, opt, jax.random.PRNGKey(0)), sspecs)
             jitted6 = jax.jit(
                 make_train_step(cfg, opt, constant(0.3), mesh=mesh, global_batch=B,
                                 plan_rules=default_rules(
                                     opt, fidelity=fidelity_presets()["adc6"])),
-                in_shardings=(named(train_state_specs(cfg, opt, mesh)),
-                              named(batch_specs(cfg, mesh, B))))
+                in_shardings=(sspecs, named(batch_specs(cfg, mesh, B))))
             st6, m6 = jitted6(st, batch)
         assert np.isfinite(float(m6["loss"])) and np.isfinite(float(m6["grad_norm"]))
         changed = any(
@@ -297,13 +301,13 @@ def test_compressed_psum_shardmap():
     """Quantized gradient all-reduce: unbiased and near-exact at 16 bits."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import compressed_psum
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64), jnp.float32)
-        f = shard_map(lambda g: compressed_psum(g, "data"), mesh=mesh,
-                      in_specs=P("data", None), out_specs=P(None))
+        f = jax.shard_map(lambda g: compressed_psum(g, "data"), mesh=mesh,
+                          in_specs=P("data", None), out_specs=P(None))
         got = np.asarray(f(x))[0] if False else np.asarray(f(x))
         ref = np.asarray(x.sum(0))
         err = np.abs(got - ref).max() / (np.abs(ref).max() + 1e-9)

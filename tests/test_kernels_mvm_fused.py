@@ -184,10 +184,10 @@ def test_no_quantized_operand_crosses_hbm(transpose, double_buffer):
             ((IO_BITS - 1, B, contract), "float32"),
         ],
     )
-    # the boundary carries exactly: SMEM exponent, float activation, planes
+    # the boundary carries exactly: SMEM DAC scale 2^F, float activation, planes
     shapes = sorted((tuple(a.shape), str(a.dtype)) for a in avals)
     assert ((B, contract), "float32") in shapes
-    assert ((1, 1), "int32") in shapes
+    assert ((1, 1), "float32") in shapes
 
 
 def test_no_noise_grid_crosses_hbm():

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.models.common import LMConfig, MLACfg, SSMCfg
 from repro.serve import kv_pages
@@ -173,7 +174,7 @@ def test_engine_under_mesh_matches_solo():
     same tokens as the unsharded path."""
     cfg = CFGS["attn"]
     params = _params(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     trace = _mk_trace(cfg, seed=5, n=3, prompt_lens=(4, 6), out_lens=(3, 6))
     results = {}
     for name, m in (("host", None), ("mesh", mesh)):
