@@ -469,23 +469,32 @@ def init_params(cfg: LMConfig, key) -> dict:
     return params
 
 
+# Named scopes of the vocabulary-wide work: their names reach every HLO
+# instruction's metadata (op_name), through autodiff and remat, so a device
+# trace can attribute time to them.
+EMBED_SCOPE = "lm.embed"
+HEAD_SCOPE = "lm.head"
+
+
 def _embed_in(cfg: LMConfig, params, tokens_or_embeds):
-    if cfg.input_mode == "tokens":
-        h = params["embed"].astype(cfg.dtype)[tokens_or_embeds]
-    else:
-        h = tokens_or_embeds.astype(cfg.dtype)
-    if cfg.embed_scale:
-        h = h * jnp.asarray(jnp.sqrt(float(cfg.d_model)), cfg.dtype)
-    return h
+    with jax.named_scope(EMBED_SCOPE):
+        if cfg.input_mode == "tokens":
+            h = params["embed"].astype(cfg.dtype)[tokens_or_embeds]
+        else:
+            h = tokens_or_embeds.astype(cfg.dtype)
+        if cfg.embed_scale:
+            h = h * jnp.asarray(jnp.sqrt(float(cfg.d_model)), cfg.dtype)
+        return h
 
 
 def _head_out(cfg: LMConfig, params, h):
-    h = rms_norm(params["final_ln"], h, cfg.norm_eps)
-    if cfg.tie_embeddings and cfg.input_mode == "tokens":
-        logits = h @ params["embed"].astype(h.dtype).T
-    else:
-        logits = h @ params["lm_head"].astype(h.dtype)
-    return softcap(logits, cfg.softcap_final)
+    with jax.named_scope(HEAD_SCOPE):
+        h = rms_norm(params["final_ln"], h, cfg.norm_eps)
+        if cfg.tie_embeddings and cfg.input_mode == "tokens":
+            logits = h @ params["embed"].astype(h.dtype).T
+        else:
+            logits = h @ params["lm_head"].astype(h.dtype)
+        return softcap(logits, cfg.softcap_final)
 
 
 def forward(cfg: LMConfig, params, inputs, remat: bool = True, shard_fn=None):
@@ -541,13 +550,15 @@ def hidden(cfg: LMConfig, params, inputs, remat=True, shard_fn=None, wshard=None
 def _nll_of_chunk(cfg: LMConfig, params, h_c, labels_c):
     """Fused head matmul + stable CE for one token chunk (f32 math bounded
     to the chunk — the full [B,S,V] f32 logits never exist)."""
-    logits = _head_out(cfg, params, h_c).astype(jnp.float32)
-    m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
-    shifted = logits - m
-    lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
-    onehot = jax.nn.one_hot(labels_c, cfg.vocab, dtype=jnp.bfloat16)
-    ll = jnp.einsum("bsv,bsv->bs", shifted.astype(jnp.bfloat16), onehot, preferred_element_type=jnp.float32)
-    return lse - ll
+    logits = _head_out(cfg, params, h_c)
+    with jax.named_scope(HEAD_SCOPE):
+        logits = logits.astype(jnp.float32)
+        m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
+        shifted = logits - m
+        lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
+        onehot = jax.nn.one_hot(labels_c, cfg.vocab, dtype=jnp.bfloat16)
+        ll = jnp.einsum("bsv,bsv->bs", shifted.astype(jnp.bfloat16), onehot, preferred_element_type=jnp.float32)
+        return lse - ll
 
 
 LOSS_CHUNK = 1024

@@ -44,6 +44,13 @@ from repro.models.common import LMConfig, OuterProductGrad, XbarWeight
 from repro.optim import PantherConfig, panther
 
 
+# Named scopes of the step's own phases (``jax.named_scope``): their names
+# reach every HLO instruction's metadata (op_name), so a device trace can
+# attribute time to the plane dequantize and to the update.
+DEQUANTIZE_SCOPE = "step.dequantize"
+UPDATE_SCOPE = "step.update"
+
+
 def _is_opg(x) -> bool:
     return isinstance(x, OuterProductGrad)
 
@@ -396,44 +403,45 @@ def make_train_step(
         _fid_scope = lambda: dist_fid.use_sharded_fidelity(_fid_ctx)
 
     def _train_step(state: TrainState, batch):
-        params = panther.materialize_split(state.digital, state.sliced, opt_cfg)
-        plan_t = plan0
-        if operand_grads:
-            # flattened tokens per differentiated forward (one microbatch)
-            inp = batch["inputs"]
-            if cfg.input_mode == "tokens":
-                tokens = inp.shape[-2] * inp.shape[-1]
-            else:
-                tokens = inp.shape[-3] * inp.shape[-2]
-            # expert-group leaves stash per-expert capacity buffers, not
-            # per-token ones: the custom-vjp cotangent aval must match the
-            # grouped einsum's dispatch shape exactly, so recompute the MoE
-            # capacity token count (G groups x C slots) the model will use
-            expert_tokens = None
-            if cfg.moe is not None:
-                from repro.models.mlp import MOE_GROUP
+        with jax.named_scope(DEQUANTIZE_SCOPE):
+            params = panther.materialize_split(state.digital, state.sliced, opt_cfg)
+            plan_t = plan0
+            if operand_grads:
+                # flattened tokens per differentiated forward (one microbatch)
+                inp = batch["inputs"]
+                if cfg.input_mode == "tokens":
+                    tokens = inp.shape[-2] * inp.shape[-1]
+                else:
+                    tokens = inp.shape[-3] * inp.shape[-2]
+                # expert-group leaves stash per-expert capacity buffers, not
+                # per-token ones: the custom-vjp cotangent aval must match the
+                # grouped einsum's dispatch shape exactly, so recompute the MoE
+                # capacity token count (G groups x C slots) the model will use
+                expert_tokens = None
+                if cfg.moe is not None:
+                    from repro.models.mlp import MOE_GROUP
 
-                sg = min(MOE_GROUP, tokens)
-                cap = max(
-                    cfg.moe.top_k,
-                    int(cfg.moe.capacity_factor * sg * cfg.moe.top_k / cfg.moe.n_experts),
-                )
-                expert_tokens = (tokens // sg) * cap
-            if use_plan:
-                # trace-time re-resolution: token-dependent rules (the
-                # operand-stash fallback) see the real microbatch size.
-                # NOT on the mesh path: the sharding specs (gnamed/pnamed)
-                # were built from the build-time plan, and a leaf flipping
-                # operand->dense here would pair a dense gradient with an
-                # OuterProductGrad spec subtree — token-dependent rules are
-                # inert under a mesh (tokens are unknown at spec-build time).
-                if rules is not None and mesh is None:
-                    plan_t = planlib.resolve_plan(params, rules, tokens=tokens)
-                params = panther.operandize(params, state.sliced, tokens, cfg.dtype,
-                                            plan=plan_t, expert_tokens=expert_tokens)
-            else:
-                params = panther.operandize(params, state.sliced, tokens, cfg.dtype,
-                                            fid=fidelity)
+                    sg = min(MOE_GROUP, tokens)
+                    cap = max(
+                        cfg.moe.top_k,
+                        int(cfg.moe.capacity_factor * sg * cfg.moe.top_k / cfg.moe.n_experts),
+                    )
+                    expert_tokens = (tokens // sg) * cap
+                if use_plan:
+                    # trace-time re-resolution: token-dependent rules (the
+                    # operand-stash fallback) see the real microbatch size.
+                    # NOT on the mesh path: the sharding specs (gnamed/pnamed)
+                    # were built from the build-time plan, and a leaf flipping
+                    # operand->dense here would pair a dense gradient with an
+                    # OuterProductGrad spec subtree — token-dependent rules are
+                    # inert under a mesh (tokens are unknown at spec-build time).
+                    if rules is not None and mesh is None:
+                        plan_t = planlib.resolve_plan(params, rules, tokens=tokens)
+                    params = panther.operandize(params, state.sliced, tokens, cfg.dtype,
+                                                plan=plan_t, expert_tokens=expert_tokens)
+                else:
+                    params = panther.operandize(params, state.sliced, tokens, cfg.dtype,
+                                                fid=fidelity)
         if pshard is not None:
             # keep the compute copy ZeRO-sharded in storage; the per-layer
             # all-gather happens inside the layer scan, not up front
@@ -515,15 +523,16 @@ def make_train_step(
                 [o if a is None else a / microbatches for a, o in zip(leaves_acc, leaves_ops)]
             )
 
-        lr = lr_schedule(state.step)
-        new_digital, new_sliced = panther.update_split(
-            grads, state.digital, state.sliced, state.step, lr, opt_cfg, rng=state.rng,
-            plan=plan_t,
-        )
+        with jax.named_scope(UPDATE_SCOPE):
+            lr = lr_schedule(state.step)
+            new_digital, new_sliced = panther.update_split(
+                grads, state.digital, state.sliced, state.step, lr, opt_cfg, rng=state.rng,
+                plan=plan_t,
+            )
+            gnorm = panther.global_grad_norm(grads)
         new_state = TrainState(
             step=state.step + 1, digital=new_digital, sliced=new_sliced, rng=state.rng
         )
-        gnorm = panther.global_grad_norm(grads)
         return new_state, {"loss": loss_val, "lr": lr, "grad_norm": gnorm}
 
     def train_step(state: TrainState, batch):
