@@ -6,6 +6,14 @@ exactly the hardware's. Grid = (B/bb, N/bn, M/128) with the row-tile dim
 innermost ("arbitrary"): the f32 accumulator lives in VMEM scratch across
 contraction tiles and is written out once.
 
+Token blocks: ``bb`` is chosen from the flattened token count
+(``pick_token_block``): the largest divisor of ``B`` on the 8-row granule,
+up to ``BB_CAP`` = 128 rows and the VMEM limit. Each plane tile is DMA'd,
+converted and loaded into the MXU once per token block, so a 512-token read
+does that 4 times per tile where 8-row blocks did it 64 times; small token
+counts (decode, expert reads) read in one block of the whole batch. Each
+output row depends only on its own input row: any ``bb`` gives the same bits.
+
 Packed schedule (per crossbar tile, see ``_tile_compute``):
 
 1. **Bit-plane packing** — the ``io_bits-1`` sign·magnitude planes of the
@@ -13,15 +21,21 @@ Packed schedule (per crossbar tile, see ``_tile_compute``):
    ``[(io_bits-1)·bb, 128]`` MXU operand (the seed kernel re-derived each
    plane per slice and issued a ``[bb, 128]`` matmul per (slice, bit):
    ``S·(io_bits-1)`` = 120 dots at ~6% MXU row utilization).
-2. **Slice-stacked weights** — the S digit planes concatenate along columns
-   into ``[128, S·bn]``, so ONE ``dot_general`` computes every (bit, slice)
-   analog column current of the tile.
-3. **ADC** — clip/quantize applies elementwise on the ``[(io_bits-1)·bb,
-   S·bn]`` block with the per-slice full scale laid out along the stacked
-   column blocks.
-4. **Digital shift-and-add** — the static ``2^t`` weights fold over the
-   row blocks and ``16^s`` over the column blocks (cheap VPU adds), then the
-   tile lands in the f32 accumulator.
+2. **Slice-stacked weights** — the S digit planes, converted once per tile
+   and token block, concatenate along columns into ``[128, S·bn]``, so ONE
+   ``dot_general`` computes every (bit, slice) analog column current of the
+   tile.
+3. **ADC in the code domain** (``_adc_fold``) — every ADC step
+   ``2·128·plane_max[s] / 2^adc_bits`` is a power of two, so the digits are
+   scaled by ``1/step_s`` as the tile is converted (exact in bf16) and the
+   MXU yields the ``[(io_bits-1)·bb, S·bn]`` currents in step units; the ADC
+   is then a round and a clip to ``±2^(adc_bits-1)``: integer codes, no
+   division and no rescale on the grid.
+4. **Digital shift-and-add** — the static ``2^t`` weights fold the codes over
+   the row blocks and ``16^s·step_s`` over the column blocks (cheap VPU
+   adds), then the tile lands in the f32 accumulator. Scaling by a power of
+   two commutes with every rounded add, so this is bit-identical to
+   ``core.mvm._adc`` followed by the ``2^t`` and ``16^s`` folds (tested).
 
 ``adc_bits=None`` takes an in-kernel ideal-ADC branch: bit-streaming is
 exact under an ideal ADC, so the kernel contracts ``x_q`` against the
@@ -74,7 +88,9 @@ SMEM offset pair so sharded lowerings reproduce the single-host pattern.
 """
 from __future__ import annotations
 
+import collections
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -87,8 +103,44 @@ from repro.core.slicing import LOGICAL_BITS, SliceSpec
 from repro.kernels.common import pick_block
 
 XBAR_ROWS = 128
-DEFAULT_BB = 8
+TOKEN_GRANULE = 8  # sublane granule of the token (batch) block
 DEFAULT_BN = 256
+# Token blocks: one plane tile is DMA'd, converted and loaded into the MXU
+# once per token block, so the block is as tall as the flattened token count
+# allows, up to BB_CAP rows and the VMEM limit for the block's buffers.
+BB_CAP = 128
+VMEM_LIMIT = 32 * 2**20  # scoped-VMEM limit the read kernels compile under
+
+
+def read_vmem_bytes(bb: int, bn: int, contract: int, spec: SliceSpec,
+                    io_bits: int, adc_bits: int | None) -> int:
+    """VMEM one read program holds at token block ``bb``: the f32 product
+    grid ``[(io_bits-1)·bb, S·bn]`` (``[bb, S·bn]`` at the ideal ADC), the f32
+    activation strip ``[bb, contract]`` and the output block, both
+    double-buffered, the two int8 plane slots and the tile's converted
+    MXU operand."""
+    S = spec.n_slices
+    rows = bb if adc_bits is None else (io_bits - 1) * bb
+    grid = rows * S * bn * 4
+    strip = 2 * bb * contract * 4
+    out = 2 * bb * bn * 4
+    planes = 2 * S * XBAR_ROWS * bn
+    w_cat = S * XBAR_ROWS * bn * (4 if adc_bits is None else 2)
+    return grid + strip + out + planes + w_cat
+
+
+def pick_token_block(B: int, bn: int, contract: int, spec: SliceSpec,
+                     io_bits: int, adc_bits: int | None) -> int:
+    """Tallest token block for a read of ``B`` tokens: the largest divisor of
+    ``B`` on the 8-row granule up to ``BB_CAP`` whose buffers fit
+    ``VMEM_LIMIT``. Up to the cap that is ``B`` itself; counts with no such
+    divisor keep ``pick_block``'s fallback."""
+    best = pick_block(B, TOKEN_GRANULE, granule=TOKEN_GRANULE)
+    for cand in range(TOKEN_GRANULE, min(B, BB_CAP) + 1, TOKEN_GRANULE):
+        if B % cand == 0 and read_vmem_bytes(
+                cand, bn, contract, spec, io_bits, adc_bits) <= VMEM_LIMIT:
+            best = cand
+    return best
 
 
 def _dac_block(x, scale, io_bits: int):
@@ -129,10 +181,47 @@ def read_offsets(dev, spec: SliceSpec, tile_idx, col0, bn: int, transpose: bool)
     return jnp.concatenate(outs, axis=1)  # [1, S*bn]
 
 
+def adc_steps(spec: SliceSpec, adc_bits: int) -> tuple:
+    """Per-slice ADC step ``2·128·plane_max[s] / 2^adc_bits`` (the
+    ``core.mvm._adc`` quantizer over the ``±128·plane_max[s]`` full scale).
+    ``plane_max[s] = 2^(bits[s]-1)``, so every step is a power of two: scaling
+    by one commutes with every rounded f32 add and multiply, which is what
+    lets the kernel run the ADC on integer codes (asserted here, at trace
+    time)."""
+    steps = tuple(2.0 * XBAR_ROWS * pm / 2**adc_bits for pm in spec.plane_max)
+    assert all(math.frexp(st)[0] == 0.5 for st in steps), steps
+    return steps
+
+
+def _adc_fold(y, *, spec: SliceSpec, io_bits: int, adc_bits: int, bb: int, bn: int):
+    """Finite-ADC epilogue of one crossbar tile in the code domain: the
+    ``[(io_bits-1)·bb, S·bn]`` column currents in units of their slice's ADC
+    step (bit blocks down, slice blocks across) -> f32 ``[bb, bn]``.
+
+    The ADC is a round and a clip to the integer codes ``±2^(adc_bits-1)``:
+    no division, no rescale. The ``2^t`` bit fold sums the codes and
+    ``step_s`` rides in the ``16^s`` slice-fold constants. Every step being a
+    power of two (``adc_steps``), each partial sum is ``step_s`` times the
+    current-domain form's (``core.mvm._adc``, then the folds): the same bits."""
+    S = spec.n_slices
+    steps = adc_steps(spec, adc_bits)
+    lim = float(2 ** (adc_bits - 1))
+    c = jnp.clip(jnp.round(y), -lim, lim)
+    # shift-and-add, bit half: fold 2^t over the stacked row blocks
+    z = c[0:bb]
+    for t in range(1, io_bits - 1):
+        z = z + c[t * bb:(t + 1) * bb] * float(2**t)
+    # slice half: fold 16^s · step_s over the stacked column blocks
+    acc = z[:, 0:bn] * steps[0]
+    for s in range(1, S):
+        acc = acc + z[:, s * bn:(s + 1) * bn] * float(2 ** (LOGICAL_BITS * s) * steps[s])
+    return acc
+
+
 def _tile_compute(xq, w, *, spec: SliceSpec, io_bits: int, adc_bits: int | None,
                   transpose: bool = False, dev=None, tile_idx=None, col0=None):
     """Product-grid contribution of one crossbar tile (pure array -> array;
-    shared by the Pallas kernel body and the jaxpr dot-count check).
+    shared by the Pallas kernel body and the jaxpr primitive checks).
 
     xq int32 [bb, 128] input block; w int8 [S, 128, bn] digit-plane block
     ([S, bn, 128] when ``transpose``). Returns f32 [bb, bn]. ``dev`` with
@@ -141,19 +230,16 @@ def _tile_compute(xq, w, *, spec: SliceSpec, io_bits: int, adc_bits: int | None,
     """
     S = spec.n_slices
     if transpose:
-        w_cat = jnp.concatenate([w[s].astype(jnp.float32) for s in range(S)], axis=0)
-        dims = (((1,), (1,)), ((), ()))  # [*, 128] x [S*bn, 128] -> [*, S*bn]
-        bn = w.shape[1]
+        dims, axis, bn = (((1,), (1,)), ((), ())), 0, w.shape[1]  # [*, 128] x [S*bn, 128]
     else:
-        w_cat = jnp.concatenate([w[s].astype(jnp.float32) for s in range(S)], axis=1)
-        dims = (((1,), (0,)), ((), ()))  # [*, 128] x [128, S*bn] -> [*, S*bn]
-        bn = w.shape[2]
-
+        dims, axis, bn = (((1,), (0,)), ((), ())), 1, w.shape[2]  # [*, 128] x [128, S*bn]
     noisy = dev is not None and dev.read_noise > 0.0
+
     if adc_bits is None:
         # ideal ADC: bit-streaming is exact -> contract the full input once.
         # xq carries up to io_bits-1 = 15 magnitude bits, more than one bf16
         # pass holds: HIGHEST (f32 contraction) keeps every product exact.
+        w_cat = jnp.concatenate([w[s].astype(jnp.float32) for s in range(S)], axis=axis)
         z = jax.lax.dot_general(
             xq.astype(jnp.float32), w_cat, dims,
             precision=jax.lax.Precision.HIGHEST,
@@ -164,50 +250,45 @@ def _tile_compute(xq, w, *, spec: SliceSpec, io_bits: int, adc_bits: int | None,
             # offset: the streamed shift-and-add folds it with sum(2^t)
             offs = read_offsets(dev, spec, tile_idx, col0, bn, transpose)
             z = z + offs * float(2 ** (io_bits - 1) - 1)
-    else:
-        bb = xq.shape[0]
-        mag_bits = io_bits - 1
-        sx = jnp.sign(xq)
-        mx = jnp.abs(xq)
-        # bit-plane packed operand, extracted once per tile: [(io_bits-1)*bb, 128]
-        xp = jnp.concatenate(
-            [((mx >> t) & 1) * sx for t in range(mag_bits)], axis=0
-        ).astype(jnp.float32)
-        # bit planes (0/±1) and digits (|d| <= plane_max <= 128) are exact in
-        # bf16 and every column sum (<= 128 * 128) is exact in the f32
-        # accumulator: one bf16 MXU pass computes the exact currents
-        y = jax.lax.dot_general(
-            xp.astype(jnp.bfloat16), w_cat.astype(jnp.bfloat16), dims,
-            preferred_element_type=jnp.float32,
-        )  # [(io_bits-1)*bb, S*bn] — every (bit, slice) column current at once
-        if noisy:
-            # per-ADC-channel offset on the raw column current, pre-ADC
-            y = y + read_offsets(dev, spec, tile_idx, col0, bn, transpose)
-        # elementwise ADC (shared SAR model from core.mvm) with the per-slice
-        # full scale laid out along the stacked column blocks
-        fs = jnp.concatenate(
-            [jnp.full((1, bn), float(XBAR_ROWS * spec.plane_max[s]), jnp.float32)
-             for s in range(S)],
-            axis=1,
-        )
-        y = _adc(y, fs, adc_bits)
-        # shift-and-add, bit half: fold 2^t over the stacked row blocks
-        z = y[0:bb]
-        for t in range(1, mag_bits):
-            z = z + y[t * bb:(t + 1) * bb] * float(2**t)
+        # shift-and-add, slice half: fold 16^s over the stacked column blocks
+        acc = z[:, 0:bn]
+        for s in range(1, S):
+            acc = acc + z[:, s * bn:(s + 1) * bn] * float(2 ** (LOGICAL_BITS * s))
+        return acc
 
-    # shift-and-add, slice half: fold 16^s over the stacked column blocks
-    acc = z[:, 0:bn]
-    for s in range(1, S):
-        acc = acc + z[:, s * bn:(s + 1) * bn] * float(2 ** (LOGICAL_BITS * s))
-    return acc
+    # the tile's digits in ADC step units, once per tile: |d| <= 128 times a
+    # power of two stays exact in bf16, so the MXU sums the currents already
+    # divided by the step (exactly: every column sum is a multiple of 1/step
+    # under 128 * 128 / step)
+    inv_step = [1.0 / st for st in adc_steps(spec, adc_bits)]
+    w_cat = jnp.concatenate(
+        [(w[s].astype(jnp.float32) * inv_step[s]).astype(jnp.bfloat16) for s in range(S)],
+        axis=axis,
+    )
+    bb = xq.shape[0]
+    sx = jnp.sign(xq)
+    mx = jnp.abs(xq)
+    # bit-plane packed operand, extracted once per tile: [(io_bits-1)*bb, 128]
+    xp = jnp.concatenate(
+        [((mx >> t) & 1) * sx for t in range(io_bits - 1)], axis=0
+    ).astype(jnp.float32)
+    # one bf16 MXU pass computes every (bit, slice) column current at once
+    y = jax.lax.dot_general(
+        xp.astype(jnp.bfloat16), w_cat, dims,
+        preferred_element_type=jnp.float32,
+    )  # [(io_bits-1)*bb, S*bn]
+    if noisy:
+        # per-ADC-channel offset on the raw column current, pre-ADC
+        inv_row = jnp.concatenate(
+            [jnp.full((1, bn), v, jnp.float32) for v in inv_step], axis=1)
+        y = y + read_offsets(dev, spec, tile_idx, col0, bn, transpose) * inv_row
+    return _adc_fold(y, spec=spec, io_bits=io_bits, adc_bits=adc_bits, bb=bb, bn=bn)
 
 
-def tile_dot_count(spec: SliceSpec, io_bits: int = 16, adc_bits: int | None = None,
-                   transpose: bool = False, bb: int = DEFAULT_BB, bn: int = DEFAULT_BN) -> int:
-    """Number of MXU ``dot_general`` ops the kernel issues per crossbar tile
-    (jaxpr-counted on the exact tile body the kernel runs). The packed
-    schedule is 1; the seed schedule was ``S * (io_bits - 1)``."""
+def tile_primitives(spec: SliceSpec, io_bits: int = 16, adc_bits: int | None = None,
+                    transpose: bool = False, bb: int = TOKEN_GRANULE,
+                    bn: int = DEFAULT_BN) -> collections.Counter:
+    """Primitive counts of the exact tile body the kernel runs (its jaxpr)."""
     wshape = (spec.n_slices, bn, XBAR_ROWS) if transpose else (spec.n_slices, XBAR_ROWS, bn)
     fn = functools.partial(
         _tile_compute, spec=spec, io_bits=io_bits, adc_bits=adc_bits, transpose=transpose
@@ -215,7 +296,15 @@ def tile_dot_count(spec: SliceSpec, io_bits: int = 16, adc_bits: int | None = No
     jaxpr = jax.make_jaxpr(fn)(
         jnp.zeros((bb, XBAR_ROWS), jnp.int32), jnp.zeros(wshape, jnp.int8)
     )
-    return sum(1 for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "dot_general")
+    return collections.Counter(eqn.primitive.name for eqn in jaxpr.jaxpr.eqns)
+
+
+def tile_dot_count(spec: SliceSpec, io_bits: int = 16, adc_bits: int | None = None,
+                   transpose: bool = False, bb: int = TOKEN_GRANULE,
+                   bn: int = DEFAULT_BN) -> int:
+    """Number of MXU ``dot_general`` ops the kernel issues per crossbar tile.
+    The packed schedule is 1; the seed schedule was ``S * (io_bits - 1)``."""
+    return tile_primitives(spec, io_bits, adc_bits, transpose, bb, bn)["dot_general"]
 
 
 def _mvm_kernel(x_ref, planes_ref, out_ref, acc_ref, *, spec, io_bits, adc_bits, nk,
@@ -247,13 +336,14 @@ def mvm_sliced(
     spec: SliceSpec,
     io_bits: int = 16,
     adc_bits: int | None = None,
-    bb: int = DEFAULT_BB,
+    bb: int | None = None,
     bn: int = DEFAULT_BN,
     interpret: bool = False,
     transpose: bool = False,
 ) -> jax.Array:
     """planes int8 [S,M,N]; x_q int32 [B,M] -> f32 [B,N] (product-grid).
-    With ``transpose``: x_q int32 [B,N] -> f32 [B,M] (the MᵀVM read)."""
+    With ``transpose``: x_q int32 [B,N] -> f32 [B,M] (the MᵀVM read).
+    ``bb=None`` takes the token block ``pick_token_block`` chooses."""
     S, M, N = planes.shape
     B = x_q.shape[0]
     contract, out_dim = (N, M) if transpose else (M, N)
@@ -261,7 +351,9 @@ def mvm_sliced(
     assert contract % XBAR_ROWS == 0, (
         f"contraction dim {contract} must be a multiple of crossbar rows ({XBAR_ROWS})"
     )
-    bb, bn = pick_block(B, bb, granule=8), pick_block(out_dim, bn)
+    bn = pick_block(out_dim, bn)
+    bb = (pick_token_block(B, bn, contract, spec, io_bits, adc_bits) if bb is None
+          else pick_block(B, bb, granule=TOKEN_GRANULE))
     nk = contract // XBAR_ROWS
     grid = (B // bb, out_dim // bn, nk)
     if transpose:
@@ -283,6 +375,7 @@ def mvm_sliced(
         out_shape=jax.ShapeDtypeStruct((B, out_dim), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT,
         ),
         interpret=interpret,
         name="panther_mvm_sliced_t" if transpose else "panther_mvm_sliced",
@@ -383,7 +476,7 @@ def mvm_sliced_fused(
     spec: SliceSpec,
     io_bits: int = 16,
     adc_bits: int | None = None,
-    bb: int = DEFAULT_BB,
+    bb: int | None = None,
     bn: int = DEFAULT_BN,
     interpret: bool = False,
     transpose: bool = False,
@@ -402,7 +495,8 @@ def mvm_sliced_fused(
     in tests). ``double_buffer=True`` selects the in-kernel crossbar-tile
     loop with 2-slot DMA prefetch of the digit planes; ``False`` keeps the
     3-D grid of ``mvm_sliced`` (used for equivalence testing and as the
-    conservative fallback).
+    conservative fallback). ``bb=None`` takes the token block
+    ``pick_token_block`` chooses; any ``bb`` gives the same bits.
 
     ``dev`` (static, a ``models.common.DeviceModel`` with ``read_noise > 0``)
     enables the frozen per-ADC-channel read offsets (module docstring);
@@ -418,7 +512,9 @@ def mvm_sliced_fused(
     assert contract % XBAR_ROWS == 0, (
         f"contraction dim {contract} must be a multiple of crossbar rows ({XBAR_ROWS})"
     )
-    bb, bn = pick_block(B, bb, granule=8), pick_block(out_dim, bn)
+    bn = pick_block(out_dim, bn)
+    bb = (pick_token_block(B, bn, contract, spec, io_bits, adc_bits) if bb is None
+          else pick_block(B, bb, granule=TOKEN_GRANULE))
     nk = contract // XBAR_ROWS
     noisy = dev is not None and dev.read_noise > 0.0
     f_spec = pl.BlockSpec(
@@ -464,6 +560,7 @@ def mvm_sliced_fused(
             out_shape=jax.ShapeDtypeStruct((B, out_dim), jnp.float32),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
+                vmem_limit_bytes=VMEM_LIMIT,
             ),
             interpret=interpret,
             name=name + "_db",
@@ -490,6 +587,7 @@ def mvm_sliced_fused(
         out_shape=jax.ShapeDtypeStruct((B, out_dim), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT,
         ),
         interpret=interpret,
         name=name,

@@ -44,11 +44,6 @@ from repro.core.slicing import SliceSpec
 from . import kernel as _k
 from . import ref as _ref
 
-# token-axis granule of the kernel batch grid: padding the flattened token
-# count up to this keeps the bb=8 sublane block (pick_block would otherwise
-# degrade to tiny odd blocks for prime token counts)
-BATCH_GRANULE = 8
-
 
 def _normalize_read_device(device):
     """None unless the read path is non-ideal (an ideal or write-only
@@ -162,7 +157,9 @@ def mvm_sliced_fused_batched(
     assert x.shape[-1] == contract, (x.shape, planes.shape, transpose)
     x2 = x.reshape(-1, contract)
     t = x2.shape[0]
-    pad = (-t) % BATCH_GRANULE
+    # pad to the kernel's 8-row token granule (pick_token_block would
+    # otherwise degrade to tiny odd blocks for prime token counts)
+    pad = (-t) % _k.TOKEN_GRANULE
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     out = mvm_sliced_fused(
@@ -190,9 +187,10 @@ def mvm_sliced_batched(
     when ``transpose``) with arbitrary leading dims -> f32 [..., N] ([..., M]).
 
     All leading dims flatten into one token axis of the 2-D engine — the
-    kernel grid tiles it in ``bb=8`` sublane blocks, so the per-crossbar-tile
-    MXU operand stays ``[(io_bits-1)·bb, 128]`` regardless of token count
-    (one dot per tile per bit-block; jaxpr-asserted in tests). Each output
+    kernel grid tiles it in token blocks of up to ``kernel.BB_CAP`` rows
+    (``kernel.pick_token_block``), so each crossbar tile issues one
+    ``[(io_bits-1)·bb, 128]`` MXU operand per token block (one dot per tile;
+    jaxpr-asserted in tests) whatever the token count. Each output
     row depends only on its own input row and the ADC applies elementwise,
     so the flattened form is bit-identical to per-token vector reads
     (property-tested); zero padding rows (sign 0 ⇒ all-zero bit planes) are
@@ -203,7 +201,7 @@ def mvm_sliced_batched(
     assert x_q.shape[-1] == contract, (x_q.shape, planes.shape, transpose)
     x2 = x_q.reshape(-1, contract)
     t = x2.shape[0]
-    pad = (-t) % BATCH_GRANULE
+    pad = (-t) % _k.TOKEN_GRANULE
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     out = mvm_sliced(
@@ -305,7 +303,7 @@ def mvm_sliced_sharded(
     x2 = x_q.reshape(-1, contract)
     t = x2.shape[0]
     # pad so every data shard lands on the kernel's token granule
-    pad = (-t) % (BATCH_GRANULE * dsize)
+    pad = (-t) % (_k.TOKEN_GRANULE * dsize)
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
 

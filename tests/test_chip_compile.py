@@ -91,3 +91,29 @@ def test_mvm_sliced_fused_compiles(one_chip, adc_bits, transpose, double_buffer)
     )
     name = "panther_mvm_fused" + ("_t" if transpose else "") + ("_db" if double_buffer else "")
     _assert_kernel(c, name)
+
+
+PHI4_READS = {  # [M, N] of the five projections a phi4-mini layer reads
+    "wqkv": (D_MODEL, 5120), "attn_wo": (D_MODEL, D_MODEL), "wi_gate": (D_MODEL, D_FF),
+    "wi_up": (D_MODEL, D_FF), "mlp_wo": (D_FF, D_MODEL),
+}
+
+
+@pytest.mark.parametrize("transpose", [False, True], ids=["mvm", "mtvm"])
+@pytest.mark.parametrize("read", sorted(PHI4_READS))
+def test_mvm_fused_token_blocks_compile(one_chip, read, transpose):
+    """A 512-token adc9 step's reads in their chosen 128-row token blocks:
+    Mosaic accepts each under the kernel's scoped-VMEM limit."""
+    m, n = PHI4_READS[read]
+    contract, out_dim = (n, m) if transpose else (m, n)
+    bb = mvm_k.pick_token_block(TOKENS, mvm_k.DEFAULT_BN, contract, DEFAULT_SPEC, 16, 9)
+    assert bb == 128
+    assert mvm_k.read_vmem_bytes(bb, mvm_k.DEFAULT_BN, contract, DEFAULT_SPEC, 16, 9) \
+        <= mvm_k.VMEM_LIMIT
+    c = _compile(
+        lambda p, x, f: mvm_k.mvm_sliced_fused(
+            p, x, f, spec=DEFAULT_SPEC, adc_bits=9, transpose=transpose),
+        one_chip,
+        ((S, m, n), jnp.int8), ((TOKENS, contract), jnp.float32), ((), jnp.int32),
+    )
+    _assert_kernel(c, "panther_mvm_fused" + ("_t" if transpose else "") + "_db")

@@ -12,6 +12,8 @@ Invariants:
   kernel-vs-ref tolerance;
 * the double-buffered DMA lowering computes the same numbers as the 3-D
   grid lowering (bit-identical: same per-tile compute in the same k order);
+* any token block gives the bits of 8-row blocks (each output row reads
+  only its own input row), and the chosen block fits the VMEM limit;
 * NOTHING quantized crosses the pallas_call boundary: no int32 operand, no
   bit-plane stack, no noise grid — jaxpr-audited via
   ``kernels.common.forbid_pallas_inputs``.
@@ -26,6 +28,7 @@ import pytest
 from repro.core.fixed_point import choose_frac_bits, counter_key_scalars, exp2i, quantize
 from repro.core.slicing import DEFAULT_SPEC
 from repro.kernels.common import forbid_pallas_inputs, pallas_input_avals
+from repro.kernels.sliced_mvm import kernel as K
 from repro.kernels.sliced_mvm import ops as O
 from repro.kernels.sliced_mvm import ref as R
 
@@ -156,6 +159,75 @@ def test_fidelity_read_fused_equals_unfused_composition():
     y_old = mvm_sliced_batched(planes, xq, SPEC, io_bits=IO_BITS,
                                adc_bits=None) * exp2i(-(xf + F))
     assert jnp.array_equal(y, y_old)
+
+
+# ---------------------------------------------------------------------------
+# token blocks: one plane tile shared by up to BB_CAP token rows
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("tokens", [8, 200, 512])
+@pytest.mark.parametrize("adc_bits", [9, None], ids=["adc9", "ideal"])
+@pytest.mark.parametrize("transpose", [False, True], ids=["mvm", "mtvm"])
+@pytest.mark.parametrize("noisy", [False, True], ids=["exact", "read_noise"])
+def test_token_block_bit_identical_to_bb8(tokens, adc_bits, transpose, noisy):
+    """The double-buffered read at the chosen token block (512 -> 128, 200 ->
+    its divisor 40, 8 -> 8) gives the bits of the same read at bb = 8: each
+    output row reads only its own input row, in the same tile order."""
+    from repro.models.common import DeviceModel
+
+    planes, _, _ = _case(m=256, n=256, b=1)
+    x = jnp.asarray(np.random.default_rng(tokens).normal(size=(tokens, 256)), jnp.float32)
+    dev = DeviceModel(read_noise=0.05, stuck_seed=3) if noisy else None
+    xf = _xf(x)
+    bb = K.pick_token_block(tokens, 256, 256, SPEC, IO_BITS, adc_bits)
+    assert bb == {8: 8, 200: 40, 512: 128}[tokens]
+
+    def read(**kw):
+        return K.mvm_sliced_fused(planes, x, xf, spec=SPEC, io_bits=IO_BITS,
+                                  adc_bits=adc_bits, transpose=transpose,
+                                  interpret=True, dev=dev, **kw)
+
+    np.testing.assert_array_equal(
+        np.asarray(read()).view(np.uint32), np.asarray(read(bb=8)).view(np.uint32))
+
+
+PHI4_READS = {  # [M, N] of the five projections a phi4-mini layer reads
+    "wqkv": (3072, 5120), "attn_wo": (3072, 3072), "wi_gate": (3072, 8192),
+    "wi_up": (3072, 8192), "mlp_wo": (8192, 3072),
+}
+
+
+@pytest.mark.parametrize("adc_bits", [9, None], ids=["adc9", "ideal"])
+@pytest.mark.parametrize("transpose", [False, True], ids=["mvm", "mtvm"])
+@pytest.mark.parametrize("read", sorted(PHI4_READS))
+def test_token_block_at_phi4_widths(read, transpose, adc_bits):
+    """A 512-token step (2 x 256) reads every phi4-mini projection in 128-row
+    token blocks, and their buffers fit the VMEM limit."""
+    m, n = PHI4_READS[read]
+    contract, out_dim = (n, m) if transpose else (m, n)
+    bn = K.DEFAULT_BN
+    assert out_dim % bn == 0
+    assert K.pick_token_block(512, bn, contract, SPEC, IO_BITS, adc_bits) == K.BB_CAP == 128
+    assert K.read_vmem_bytes(128, bn, contract, SPEC, IO_BITS, adc_bits) <= K.VMEM_LIMIT
+
+
+@pytest.mark.parametrize("tokens,contract,adc_bits,want", [
+    (1, 3072, 9, 1),        # decode: one block of the whole batch
+    (4, 3072, 9, 4),
+    (16, 3072, 9, 16),      # up to the cap the block is the batch
+    (128, 3072, 9, 128),
+    (12, 3072, 9, 6),       # off the 8-row granule: pick_block's fallback
+    (200, 3072, 9, 40),     # the largest granule divisor under the cap
+    (1024, 3072, 9, 128),   # the cap
+    (4096, 8192, None, 128),
+    (512, 22016, 9, 64),    # a strip this wide: the VMEM limit binds
+    (512, 65536, 9, 32),
+    (512, 131072, 9, 16),
+])
+def test_pick_token_block(tokens, contract, adc_bits, want):
+    bb = K.pick_token_block(tokens, K.DEFAULT_BN, contract, SPEC, IO_BITS, adc_bits)
+    assert bb == want
+    assert tokens % bb == 0
 
 
 # ---------------------------------------------------------------------------
