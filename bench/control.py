@@ -4,7 +4,8 @@ cell's own size:
 
     python3 bench/control.py --workload <cell> --seeds <n> [<n> ...]
 
-For each seed the reference (float32) runs the cell's first steps, and then
+For each seed the reference (float32; the module the configuration names,
+``bench/run.py``'s ``load_reference``) runs the cell's first steps, and then
 three stand-ins take the program's place and are compared with it exactly as
 ``bench/run.py`` compares the program:
 
@@ -34,13 +35,14 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 def stand_in_readings(cell: dict, seed: int, modes=("control", "adc_below", "half_batch")) -> dict:
     """-> {"reference": readings, <stand-in>: readings} for one seed."""
-    from bench import refmodel as R
     from bench import run
     from bench import tokens as tok
+    from bench.refmodel import Numerics
 
+    R = run.load_reference(cell)
     traffic = cell["traffic"]
     m = R.Model.from_config(cell["config"])
-    num = R.Numerics.from_traffic(traffic)
+    num = Numerics.from_traffic(traffic)
     B, S, lr, n = traffic["batch"], traffic["seq"], traffic["lr"], run.CHECK_STEPS
     key = R.seed_key(seed)
     stream = tok.TokenStream(m.vocab, S, B, seed)

@@ -28,6 +28,31 @@ columns; the weight gradient is the outer product ``x^T dy``.
 reads: ``"f32"`` (HIGHEST precision, the reference) or ``"fp8"`` (both
 operands and the cotangent rounded to float8 e4m3 under a per-tensor scale,
 the precision below the program's bfloat16, used as the control).
+
+A configuration file names its reference module under ``bench/`` by its
+top-level ``"reference"`` (this one where it names none; ``bench/run.py``'s
+``load_reference``). The harness (``bench/run.py``, ``bench/control.py``,
+``bench/metrics/mfu.train.py`` and ``tests/bench/``) uses only this
+interface of it, which every reference module provides:
+
+* ``Model.from_config(config) -> Model``: the file's ``config`` object; a
+  configuration the module does not model is an error. ``Model.vocab`` is
+  the vocabulary the traffic draws ids from.
+* ``seed_key(seed)``: the PRNG key of any whole number below 2**64.
+* ``gen_params(key, m) -> {path: array}``: the initial weights, flat, in
+  the program's parameter layout; ``nest(flat)`` makes the program's tree.
+* ``Reader(m, num)``: ``reader(key, planes, digital) -> (norms, frac_bits)``
+  per leaf, ``||w - w0||`` against the initial state of ``key``;
+  ``reader.norm`` is keyed by the crossbar leaves, whose paths the program's
+  planes must match.
+* ``reference_readings(key, m, num, mode, lr, batches) -> dict``: the first
+  steps from the initial state of ``key``, the readings ``bench.compare``
+  takes, ``mode`` ``"f32"`` (the reference) or ``"fp8"`` (the control).
+* ``describe(m) -> str``, and ``model_flops_per_token(m, seq)``: the model
+  FLOPs a trained token costs, which ``mfu.train`` counts.
+
+``Numerics`` (PANTHER's number formats) stays here: another reference
+imports it, so that every cell holds its weights in the same formats.
 """
 from __future__ import annotations
 
@@ -67,7 +92,18 @@ class Model:
 
     @classmethod
     def from_config(cls, config: dict) -> "Model":
+        """The dense decoder of a ``config`` whose ``block`` is ``dense``, or
+        whose ``pattern`` is one group of ``n_layers`` dense blocks. A key
+        this model does not read (a block of another kind, ``mla``, ``moe``,
+        a window, a soft cap) is an error: the reference would leave it out."""
         names = {f.name for f in dataclasses.fields(cls)}
+        unknown = sorted(set(config) - names - {"block", "pattern", "dtype"})
+        if unknown:
+            raise ValueError(f"the dense reference does not model {', '.join(unknown)}")
+        pattern = [list(g) for g in config.get("pattern", [[config.get("block", "dense"), config["n_layers"]]])]
+        if pattern != [["dense", config["n_layers"]]] or config.get("block", "dense") != "dense":
+            raise ValueError(f"the dense reference models one group of {config['n_layers']} dense blocks, "
+                             f"not block {config.get('block')!r}, pattern {pattern}")
         return cls(**{k: v for k, v in config.items() if k in names})
 
 
@@ -631,6 +667,20 @@ def reference_readings(key, m: Model, num: Numerics, mode: str, lr: float, batch
             out.setdefault("change1", norms)
             out["change"] = norms
     return out
+
+
+def model_flops_per_token(m: Model, seq: int) -> float:
+    """Model FLOPs of one trained token: 6x the parameters the forward
+    multiplies by (q/k/v, attention output and MLP projections, and the
+    output head once; the embedding gather counts nothing) plus 3x the causal
+    forward attention FLOPs. Recomputation does not count."""
+    per_layer = m.d_model * (m.n_heads + 2 * m.n_kv_heads) * m.head_dim \
+        + m.n_heads * m.head_dim * m.d_model + 3 * m.d_model * m.d_ff
+    matmul_params = m.n_layers * per_layer + m.vocab * m.d_model
+    # QK^T and PV: 2 FLOPs x head_dim x heads per (query, key) pair, and a
+    # query at position i sees i + 1 keys: (seq + 1) / 2 on average
+    attn_fwd = m.n_layers * 2 * 2 * m.n_heads * m.head_dim * (seq + 1) / 2
+    return 6.0 * matmul_params + 3.0 * attn_fwd
 
 
 def n_params(m: Model) -> int:

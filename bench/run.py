@@ -7,7 +7,10 @@ The cell (``BENCHMARK.json``'s ``workloads``) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``); its limits are in
 ``bench/limits/<cell>.json`` and each per-layer metric is read by
-``bench/metrics/<metric>.py``.
+``bench/metrics/<metric>.py``. The configuration file's ``config`` object
+builds the program's ``LMConfig`` (``program_config``), and its
+``reference`` names the plain reference module under ``bench/``
+(``load_reference``; ``refmodel`` where it names none).
 
 Set-up makes the weights from the seed on the device and hands them to the
 program's ``panther.init_split``, compiles the program's jitted, donated
@@ -18,8 +21,8 @@ dispatches steps back to back, each on a batch made inside the window, with
 at most ``LOOKAHEAD`` steps in flight; at the deadline it stops dispatching
 and waits for the last step. ``train_tokens_per_s`` is the tokens of every
 step dispatched over the whole elapsed time. After the window the program's
-state is freed and the plain reference (``bench/refmodel.py``) repeats the
-first steps; ``bench/compare.py`` decides ``correct``.
+state is freed and the plain reference repeats the first steps;
+``bench/compare.py`` decides ``correct``.
 
 With ``--trace 1`` the window runs under the profiler and the line carries
 the cell's per-layer metrics instead of its end-to-end ones. The last stdout
@@ -34,6 +37,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import collections  # noqa: E402
+import dataclasses  # noqa: E402
 import functools  # noqa: E402
 import gc  # noqa: E402
 import glob  # noqa: E402
@@ -41,15 +45,18 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import pathlib  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 import types  # noqa: E402
+import typing  # noqa: E402
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 LOOKAHEAD = 2  # steps in flight before the host waits for the oldest
 CHECK_STEPS = 3  # steps that set-up drives and the reference repeats
+DEFAULT_REFERENCE = "refmodel"  # bench/refmodel.py: the dense decoder
 
 
 def log(msg):
@@ -63,6 +70,7 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> dict:
     if wl is None:
         raise SystemExit(f"no workload {name!r} in BENCHMARK.json")
     (conf,) = [c for c in bm["configs"] if c["name"] == wl["config"]]
+    conf_file = json.loads((root / conf["file"]).read_text())
     limits = root / "bench" / "limits" / f"{name}.json"
 
     def mine(metrics):
@@ -72,7 +80,8 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> dict:
         "name": name,
         "chips": wl["chips"],
         "config_name": conf["name"],
-        "config": json.loads((root / conf["file"]).read_text())["config"],
+        "config": conf_file["config"],
+        "reference": conf_file.get("reference", DEFAULT_REFERENCE),
         "traffic": json.loads((root / "bench" / "traffic" / f"{wl['traffic']}.json").read_text()),
         "limits": json.loads(limits.read_text())["limits"] if limits.exists() else {},
         "end_to_end": mine(bm["end_to_end"]),
@@ -92,22 +101,64 @@ def path_str(path) -> str:
     return "/".join(str(getattr(k, "key", getattr(k, "idx", getattr(k, "name", k)))) for k in path)
 
 
-def program_objects(cell: dict):
-    """The program's config, optimizer config, plan rules and state type."""
+def load_reference(cell: dict):
+    """The plain reference module that the cell's configuration file names
+    (``"reference"``: a module under ``bench/``), ``bench/refmodel.py`` where
+    it names none. ``refmodel``'s docstring lists what a reference provides."""
+    import importlib
+
+    name = cell.get("reference", DEFAULT_REFERENCE)
+    if not re.fullmatch(r"[A-Za-z_]\w*", name):
+        raise ValueError(f"reference {name!r} is not the name of a module under bench/")
+    return importlib.import_module(f"bench.{name}")
+
+
+def _from_json(tp, value, where: str):
+    """A JSON value as the program's field type ``tp``: an object as the
+    field's dataclass (a key it does not have is an error), a list as a
+    tuple, anything else as it is."""
+    for t in (tp, *typing.get_args(tp)):
+        if dataclasses.is_dataclass(t) and isinstance(value, dict):
+            hints = typing.get_type_hints(t)
+            unknown = sorted(set(value) - set(hints))
+            if unknown:
+                raise ValueError(f"{where}: {t.__name__} has no field {', '.join(unknown)}")
+            return t(**{k: _from_json(hints[k], v, f"{where}.{k}") for k, v in value.items()})
+    if isinstance(value, list):
+        return tuple(_from_json(None, v, where) for v in value)
+    return value
+
+
+def program_config(cell: dict):
+    """The program's ``LMConfig`` from the configuration file's ``config``:
+    every key that is a field of ``LMConfig``, nested objects (``mla``,
+    ``moe``, ...) as the field's dataclass, ``pattern`` as ``[[block, count],
+    ...]`` (``[[block, n_layers]]`` where it is absent) and ``dtype`` through
+    ``jnp.dtype``. Other keys (``block``, and what only the reference reads)
+    are the reference's."""
     import jax.numpy as jnp
+    from repro.models.common import LMConfig
+
+    conf = cell["config"]
+    hints = typing.get_type_hints(LMConfig)
+    kw = {k: _from_json(hints[k], v, k) for k, v in conf.items() if k in hints and k not in ("arch_id", "dtype")}
+    if "pattern" not in kw:
+        kw["pattern"] = ((conf["block"], conf["n_layers"]),)
+    return LMConfig(arch_id=cell["config_name"], dtype=jnp.dtype(conf["dtype"]), **kw)
+
+
+def program_objects(cell: dict):
+    """The program's config, optimizer config and plan rules."""
     from repro import plan as planlib
     from repro.core import SliceSpec
-    from repro.models.common import FidelityConfig, LMConfig
+    from repro.models.common import FidelityConfig
     from repro.optim import PantherConfig
 
-    from bench import refmodel as R
+    from bench.refmodel import Numerics
 
-    conf, traffic = cell["config"], cell["traffic"]
-    num = R.Numerics.from_traffic(traffic)
-    fields = ("d_model", "n_layers", "vocab", "n_heads", "n_kv_heads", "head_dim", "d_ff", "act",
-              "rope_theta", "norm_eps", "tie_embeddings", "qk_norm")
-    cfg = LMConfig(arch_id=cell["config_name"], pattern=((conf["block"], conf["n_layers"]),),
-                   dtype=jnp.dtype(conf["dtype"]), **{k: conf[k] for k in fields})
+    traffic = cell["traffic"]
+    num = Numerics.from_traffic(traffic)
+    cfg = program_config(cell)
     opt = PantherConfig(spec=SliceSpec(bits=num.slice_bits), crs_every=traffic["crs_every"],
                         margin_bits=num.margin_bits)
     rules = None
@@ -129,13 +180,14 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, peaks: dict | N
     from repro.train import step as train_step
 
     from bench import compare as cmp
-    from bench import refmodel as R
     from bench import tokens as tok
     from bench import trace_reduce as tr
+    from bench.refmodel import Numerics
 
+    R = load_reference(cell)
     traffic = cell["traffic"]
     m = R.Model.from_config(cell["config"])
-    num = R.Numerics.from_traffic(traffic)
+    num = Numerics.from_traffic(traffic)
     B, S, lr, n_check = traffic["batch"], traffic["seq"], traffic["lr"], CHECK_STEPS
     if traffic["crs_every"] <= n_check:
         raise ValueError("a carry resolution inside the compared steps is not modelled")
@@ -163,7 +215,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, peaks: dict | N
                       donate_argnums=0)
     t0 = time.perf_counter()
     compiled = step_fn.lower(state, batch(0)).compile()
-    calls = tr.kernel_calls(compiled.as_text())
+    hlo = compiled.as_text()
+    calls = tr.kernel_calls(hlo)
     counts = collections.Counter(tr.family(n) for n in calls)
     log(f"step compiled in {time.perf_counter() - t0:.1f} s; kernels {dict(sorted(counts.items()))}")
     ma = compiled.memory_analysis()
@@ -241,7 +294,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, peaks: dict | N
         (xplane,) = glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"), recursive=True)
         summary = tr.load_profile(xplane)
         shutil.rmtree(profile_dir, ignore_errors=True)
-        ctx = types.SimpleNamespace(summary=summary, calls=calls, peaks=peaks, model=m, seq=S, tokens=steps * B * S, chips=len(devices))
+        log(f"traced window: {steps} steps, device op time {1e3 * sum(summary.op_s.values()) / steps!r} ms a step")
+        ctx = types.SimpleNamespace(summary=summary, calls=calls, hlo=hlo, steps=steps, peaks=peaks,
+                                    tokens=steps * B * S, chips=len(devices),
+                                    flops_per_token=R.model_flops_per_token(m, S))
         metrics = {}
         for spec in cell["per_layer"]:
             value = load_reader(spec["name"])(ctx)

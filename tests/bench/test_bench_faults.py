@@ -10,7 +10,7 @@ import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 
-from bench_cells import PEAKS, SEED, cmp, control, run, tiny  # noqa: E402
+from bench_cells import CELLS, PEAKS, SEED, cmp, control, run, tiny  # noqa: E402
 
 
 def broken(fault):
@@ -33,7 +33,7 @@ def broken(fault):
 
 
 @pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
-@pytest.mark.parametrize("name", ["phi4-train", "phi4-train-adc9", "chameleon-train-2k"])
+@pytest.mark.parametrize("name", sorted(CELLS))
 def test_broken_step_is_not_correct(name, fault, monkeypatch):
     from repro.train import step as train_step
 
@@ -44,8 +44,11 @@ def test_broken_step_is_not_correct(name, fault, monkeypatch):
         assert res["check"]["change_gap"]["value"] == pytest.approx(1.0)
 
 
-@pytest.mark.parametrize("name,stand_in", [("phi4-train", "control"), ("chameleon-train-2k", "control"),
-                                           ("phi4-train-adc9", "adc_below")])
+# each cell's control: ADCs a bit coarser where the cell reads through them
+STAND_INS = [(name, "adc_below" if tiny(name)["traffic"]["fidelity"] else "control") for name in sorted(CELLS)]
+
+
+@pytest.mark.parametrize("name,stand_in", STAND_INS)
 def test_control_is_not_correct(name, stand_in):
     """The reference one precision below the cell's: float8 matrix products
     for the lossless step, ADCs a bit coarser for the analog read."""

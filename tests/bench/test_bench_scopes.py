@@ -174,9 +174,8 @@ def _compile_tiny(name):
     from repro.optim.schedules import constant
     from repro.train import step as train_step
 
-    from bench import refmodel as R
-
     cell = tiny(name)
+    R = run.load_reference(cell)
     m = R.Model.from_config(cell["config"])
     cfg, opt, rules = run.program_objects(cell)
 

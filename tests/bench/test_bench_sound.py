@@ -7,10 +7,10 @@ import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 
-from bench_cells import PEAKS, SEED, run, tiny  # noqa: E402
+from bench_cells import CELLS, PEAKS, SEED, run, tiny  # noqa: E402
 
 
-@pytest.mark.parametrize("name", ["phi4-train", "phi4-train-adc9", "chameleon-train-2k"])
+@pytest.mark.parametrize("name", sorted(CELLS))
 def test_sound_run_is_correct(name):
     res = run.run_cell(tiny(name), SEED, 0.3, False, PEAKS)
     assert res["correct"], res["check"]
